@@ -85,6 +85,32 @@ def cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
 
+def lane_rows(x: jax.Array, rows: int, sub: int) -> tuple[jax.Array, int]:
+    """View the last axis of ``x`` as ``(m, LANES)`` rows, the layout Mosaic
+    tiles natively, for kernels whose blocks walk a flat extent.
+
+    Returns ``(view, block_rows)``: ``block_rows`` is ``rows`` (a multiple
+    of the sublane tile ``sub``), or -- for a view shorter than one block --
+    the whole view, padded to whole ``(sub, LANES)`` tiles, since a block
+    may only be smaller than the array in multiples of the tile.  Only a
+    ragged tail is padded; kernels mask it by the true extent, and
+    :func:`from_lane_rows` drops it again.
+    """
+    n = x.shape[-1]
+    m = cdiv(n, LANES)
+    if m < rows:
+        rows = m = round_up(m, sub)
+    if m * LANES != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, m * LANES - n)])
+    return x.reshape(x.shape[:-1] + (m, LANES)), rows
+
+
+def from_lane_rows(y: jax.Array, n: int) -> jax.Array:
+    """Inverse of :func:`lane_rows`: ``(..., m, LANES)`` -> ``(..., n)``."""
+    y = y.reshape(y.shape[:-2] + (-1,))
+    return y if y.shape[-1] == n else y[..., :n]
+
+
 # --------------------------------------------------------------------------
 # vload_pattern analogue: static decomposition of a ragged extent.
 # --------------------------------------------------------------------------
@@ -427,14 +453,18 @@ _GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 
 
 def detect_chip() -> str:
+    """Tuning-policy name of device 0.  A TPU this table does not know is
+    named after its ``device_kind`` (e.g. ``tpu_v6_lite``), which
+    :func:`resolve_tuning` maps to the generic policy -- never to another
+    chip's policy."""
     dev = jax.devices()[0]
     if dev.platform == "tpu":
-        kind = getattr(dev, "device_kind", "").lower()
+        kind = dev.device_kind.lower()
         if "v5 lite" in kind or "v5e" in kind:
             return "tpu_v5e"
-        if "v5p" in kind or "v5" in kind:
+        if "v5p" in kind or kind == "tpu v5":
             return "tpu_v5p"
-        return "tpu_v5e"
+        return "tpu_" + "_".join(kind.removeprefix("tpu").split())
     if dev.platform in _GPU_PLATFORMS:
         kind = getattr(dev, "device_kind", "").lower()
         for tag, name in (("h100", "gpu_h100"), ("h200", "gpu_h100"),
@@ -620,9 +650,12 @@ def current_backend() -> str:
 def resolve_impl(primitive: str, backend: str | None = None) -> Callable:
     backend = backend or current_backend()
     key = (primitive, backend)
+    # Registers the kernels first: a lookup before kernels/ops.py is
+    # imported would otherwise take the xla fallback below.
+    known = _known_backends()
     impl = _IMPL_REGISTRY.get(key)
     if impl is None:
-        if backend not in _known_backends():
+        if backend not in known:
             # Unknown backend *names* are user errors and fail loudly,
             # uniformly naming the route; known backends without a native
             # implementation for this route fall back below.

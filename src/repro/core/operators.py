@@ -620,7 +620,8 @@ def unitfloat8_encode(x: jax.Array) -> jax.Array:
 
 
 def unitfloat8_decode(u: jax.Array) -> jax.Array:
-    return u.astype(jnp.float32) * (2.0 / 255.0) - 1.0
+    # Widen through int32: Mosaic has no direct uint8 -> float32 cast.
+    return u.astype(jnp.int32).astype(jnp.float32) * (2.0 / 255.0) - 1.0
 
 
 # --------------------------------------------------------------------------

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import operators as alg
 from repro.core import primitives as forge
@@ -123,7 +122,7 @@ def flash_decode_gqa(mesh, q, k_cache, v_cache, k_new, v_new, slot,
         out = merge_partials(m, l, o, "model")
         return out.astype(qb.dtype), kb, vb
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None, None, None),
                   P(b, "model", None, None),
@@ -135,7 +134,7 @@ def flash_decode_gqa(mesh, q, k_cache, v_cache, k_new, v_new, slot,
         out_specs=(P(b, None, None, None, None),
                    P(b, "model", None, None),
                    P(b, "model", None, None)),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, k_new, v_new, slot, key_valid)
 
 
@@ -163,12 +162,12 @@ def flash_decode_mla(mesh, q_abs, q_rope, ckv, krope, ckv_new, krope_new,
 
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     b = dp if batch_sharded else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None, None), P(b, None, None, None),
                   P(b, "model", None), P(b, "model", None),
                   P(b, None, None), P(b, None, None), P(), P("model")),
         out_specs=(P(b, None, None, None),
                    P(b, "model", None), P(b, "model", None)),
-        check_rep=False)
+        check_vma=False)
     return fn(q_abs, q_rope, ckv, krope, ckv_new, krope_new, slot, key_valid)

@@ -23,7 +23,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import operators as alg
@@ -195,10 +194,10 @@ def moe_forward_sharded(params, cfg, x, mesh):
             specs.append(P(dp_axes, "model"))
         args.append(shared["w_out"])
         specs.append(P("model", dp_axes))
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(specs),
         out_specs=(P(dp, None, None), P(), P()),
-        check_rep=False)
+        check_vma=False)
     out, lb, rz = fn(*args)
     return out, {"lb_loss": lb, "router_z": rz}

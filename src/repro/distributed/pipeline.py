@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -69,6 +68,6 @@ def gpipe_forward(mesh, stage_fn, stage_params, x, *, n_micro,
 
     in_specs = (jax.tree.map(leaf_spec, stage_params),
                 P(*([None] * x.ndim)))
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(*([None] * x.ndim)), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(*([None] * x.ndim)), check_vma=False)
     return fn(stage_params, x)

@@ -86,7 +86,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import intrinsics as ki
@@ -94,6 +93,14 @@ from repro.core import operators as alg
 from repro.core import tuning
 
 Pytree = object
+
+
+def _shard_map(local, mesh, in_specs, out_specs):
+    """``jax.shard_map`` over ``mesh``, compiled as one program: called
+    outside ``jit``, a bare shard_map runs its body op by op and compiles
+    every op for the mesh."""
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _axis_extent(mesh, axis_name: str) -> int:
@@ -306,8 +313,7 @@ def sharded_scan(op, xs, *, axis_name, mesh, inclusive=True, overlap=True,
     def local(xs_loc):
         return run_plan(plan, (xs_loc,), overlap=overlap)
 
-    out = shard_map(local, mesh=mesh, in_specs=(P(axis_name),),
-                    out_specs=P(axis_name), check_rep=False)(xs)
+    out = _shard_map(local, mesh, (P(axis_name),), P(axis_name))(xs)
     if n_pad != n:
         out = jax.tree.map(lambda l: l[:n], out)
     return out
@@ -411,8 +417,7 @@ def sharded_mapreduce(f, op, xs, *, axis_name, mesh, overlap=True,
         return run_plan(plan, (vals_loc,), num_chunks=num_chunks,
                         overlap=overlap)
 
-    return shard_map(local, mesh=mesh, in_specs=(P(axis_name),),
-                     out_specs=P(), check_rep=False)(vals)
+    return _shard_map(local, mesh, (P(axis_name),), P())(vals)
 
 
 # ---------------------------------------------------------------------------
@@ -494,8 +499,7 @@ def _sharded_mv(primitive, f, op, A, x, *, axis_name, mesh, overlap,
     else:
         args = (A_even, x)
         in_specs = (spec_even, P(axis_name))
-    return shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(*args)
+    return _shard_map(local, mesh, in_specs, P())(*args)
 
 
 @ki.sub_backend_alias
@@ -591,8 +595,7 @@ def sharded_linear_recurrence(a, b, *, h0=None, axis_name, mesh,
     if h0 is not None:
         in_specs += (P(None, None),)
         args += (h0,)
-    h = shard_map(local, mesh=mesh, in_specs=in_specs,
-                  out_specs=P(None, axis_name, None), check_rep=False)(*args)
+    h = _shard_map(local, mesh, in_specs, P(None, axis_name, None))(*args)
     if t_pad != T:
         h = h[:, :T]
     return h
@@ -664,8 +667,7 @@ def sharded_top_k(keys, k, *, axis_name, mesh, largest=True, key_bits=None,
     def local(keys_loc):
         return run_plan(plan, (keys_loc,), overlap=overlap)
 
-    return shard_map(local, mesh=mesh, in_specs=(P(axis_name),),
-                     out_specs=(P(), P()), check_rep=False)(keys)
+    return _shard_map(local, mesh, (P(axis_name),), (P(), P()))(keys)
 
 
 # ---------------------------------------------------------------------------
@@ -757,9 +759,9 @@ def sharded_sort_pairs(keys, values, *, axis_name, mesh, descending=False,
     def local(keys_loc, values_loc):
         return run_plan(plan, (keys_loc, values_loc), overlap=overlap)
 
-    out_k, out_v = shard_map(
-        local, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
-        out_specs=(P(axis_name), P(axis_name)), check_rep=False)(keys, values)
+    out_k, out_v = _shard_map(
+        local, mesh, (P(axis_name), P(axis_name)),
+        (P(axis_name), P(axis_name)))(keys, values)
     if n_pad != n:
         out_k = out_k[:n]
         out_v = jax.tree.map(lambda l: l[:n], out_v)

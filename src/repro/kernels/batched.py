@@ -66,8 +66,7 @@ def _batched_scan_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
         for cr, ic in zip(carry_refs, jax.tree.leaves(ident_carry)):
             cr[...] = ic
 
-    x = jax.tree.unflatten(
-        treedef, [xr[...].reshape(rows, ki.LANES) for xr in x_refs])
+    x = jax.tree.unflatten(treedef, [xr[...] for xr in x_refs])
     ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, ki.LANES), 0)
     cidx = jax.lax.broadcasted_iota(jnp.int32, (rows, ki.LANES), 1)
     valid = (g * block + ridx * ki.LANES + cidx) < n
@@ -79,7 +78,7 @@ def _batched_scan_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
     for cr, nc in zip(carry_refs, jax.tree.leaves(new_carry)):
         cr[...] = nc
     for orf, o in zip(o_refs, jax.tree.leaves(out)):
-        orf[...] = o.reshape(1, -1)
+        orf[...] = o
 
 
 def batched_scan_pallas(op, xs: Pytree, *, inclusive: bool = True,
@@ -91,26 +90,26 @@ def batched_scan_pallas(op, xs: Pytree, *, inclusive: bool = True,
     B, n = leaves[0].shape
     assert all(l.shape == (B, n) for l in leaves), "batched scan: uniform leaves"
     sub = max(ki.min_tile(l.dtype)[0] for l in leaves)
-    rows = policy.nitem_scan * sub
-    block = rows * ki.LANES
-    grid = (B, ki.cdiv(n, block))
+    views = [ki.lane_rows(l, policy.nitem_scan * sub, sub) for l in leaves]
+    rows = views[0][1]
+    m = views[0][0].shape[1]
 
     kernel = functools.partial(
         _batched_scan_kernel, op, treedef, n, rows, inclusive, len(leaves))
+    spec = pl.BlockSpec((None, rows, ki.LANES), lambda b, g: (b, g, 0))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, block), lambda b, g: (b, g))
-                  for _ in leaves],
-        out_specs=[pl.BlockSpec((1, block), lambda b, g: (b, g))
-                   for _ in leaves],
-        out_shape=[jax.ShapeDtypeStruct((B, n), l.dtype) for l in leaves],
+        grid=(B, ki.cdiv(m, rows)),
+        in_specs=[spec for _ in leaves],
+        out_specs=[spec for _ in leaves],
+        out_shape=[jax.ShapeDtypeStruct((B, m, ki.LANES), l.dtype)
+                   for l in leaves],
         scratch_shapes=[pltpu.VMEM((1, 1), l.dtype) for l in leaves],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*leaves)
-    return jax.tree.unflatten(treedef, out)
+    )(*[v for v, _ in views])
+    return jax.tree.unflatten(treedef, [ki.from_lane_rows(o, n) for o in out])
 
 
 # ---------------------------------------------------------------------------
@@ -141,28 +140,30 @@ def batched_mapreduce_pallas(f, op, xs: Pytree, *,
     out_leaves, out_treedef = jax.tree.flatten(out_shape_tree)
 
     sub = max(ki.min_tile(l.dtype)[0] for l in in_leaves)
-    rows = policy.nitem_reduce * sub
-    block = rows * ki.LANES
-    grid = (B, ki.cdiv(n, block))
+    views = [ki.lane_rows(l, policy.nitem_reduce * sub, sub)
+             for l in in_leaves]
+    rows = views[0][1]
+    m = views[0][0].shape[1]
 
     kernel = functools.partial(
         mapreduce_k._mapreduce_kernel, f, op, in_treedef, out_treedef, n,
         rows, len(in_leaves), len(out_leaves), 1)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, block), lambda b, g: (b, g))
+        grid=(B, ki.cdiv(m, rows)),
+        in_specs=[pl.BlockSpec((None, rows, ki.LANES), lambda b, g: (b, g, 0))
                   for _ in in_leaves],
-        out_specs=[pl.BlockSpec((1, 1), lambda b, g: (b, 0))
+        out_specs=[pl.BlockSpec((None, 1, 1), lambda b, g: (b, 0, 0))
                    for _ in out_leaves],
-        out_shape=[jax.ShapeDtypeStruct((B, 1), l.dtype) for l in out_leaves],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, 1), l.dtype)
+                   for l in out_leaves],
         scratch_shapes=[pltpu.VMEM((rows, ki.LANES), l.dtype)
                         for l in out_leaves],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*in_leaves)
-    return jax.tree.unflatten(out_treedef, [o[:, 0] for o in out])
+    )(*[v for v, _ in views])
+    return jax.tree.unflatten(out_treedef, [o[:, 0, 0] for o in out])
 
 
 # ---------------------------------------------------------------------------

@@ -28,15 +28,17 @@ def copy_pallas(x: jax.Array, *, nitem: int | None = None,
     nitem = nitem or policy.nitem_copy
     n = x.shape[0]
     sub = ki.min_tile(x.dtype)[0]
-    block = nitem * sub * ki.LANES
-    grid = ki.cdiv(n, block)
-    return pl.pallas_call(
+    view, rows = ki.lane_rows(x, nitem * sub, sub)
+    m = view.shape[0]
+    spec = pl.BlockSpec((rows, ki.LANES), lambda g: (g, 0))
+    out = pl.pallas_call(
         _copy_kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((block,), lambda g: (g,))],
-        out_specs=pl.BlockSpec((block,), lambda g: (g,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
+        grid=(ki.cdiv(m, rows),),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((m, ki.LANES), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x)
+    )(view)
+    return ki.from_lane_rows(out, n)

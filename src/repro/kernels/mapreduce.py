@@ -56,8 +56,7 @@ def _mapreduce_kernel(f, op, in_treedef, out_treedef, n, rows, n_in, n_out,
         for ar, ia in zip(acc_refs, jax.tree.leaves(ident_acc)):
             ar[...] = ia
 
-    x = jax.tree.unflatten(
-        in_treedef, [xr[...].reshape(rows, ki.LANES) for xr in x_refs])
+    x = jax.tree.unflatten(in_treedef, [xr[...] for xr in x_refs])
     vals = f(x)
 
     ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, ki.LANES), 0)
@@ -100,17 +99,19 @@ def mapreduce_1d_pallas(f, op, xs: Pytree, *,
     out_leaves, out_treedef = jax.tree.flatten(out_shape_tree)
 
     sub = max(ki.min_tile(l.dtype)[0] for l in in_leaves)
-    rows = policy.nitem_reduce * sub
-    block = rows * ki.LANES
-    grid = ki.cdiv(n, block)
+    views = [ki.lane_rows(l, policy.nitem_reduce * sub, sub)
+             for l in in_leaves]
+    rows = views[0][1]
+    m = views[0][0].shape[0]
 
     kernel = functools.partial(
         _mapreduce_kernel, f, op, in_treedef, out_treedef, n, rows,
         len(in_leaves), len(out_leaves), 0)
     out = pl.pallas_call(
         kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((block,), lambda g: (g,)) for _ in in_leaves],
+        grid=(ki.cdiv(m, rows),),
+        in_specs=[pl.BlockSpec((rows, ki.LANES), lambda g: (g, 0))
+                  for _ in in_leaves],
         out_specs=[pl.BlockSpec((1, 1), lambda g: (0, 0)) for _ in out_leaves],
         out_shape=[jax.ShapeDtypeStruct((1, 1), l.dtype) for l in out_leaves],
         scratch_shapes=[pltpu.VMEM((rows, ki.LANES), l.dtype)
@@ -118,5 +119,5 @@ def mapreduce_1d_pallas(f, op, xs: Pytree, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*in_leaves)
+    )(*[v for v, _ in views])
     return jax.tree.unflatten(out_treedef, [o[0, 0] for o in out])

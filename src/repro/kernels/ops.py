@@ -267,7 +267,8 @@ def _segmented_mapreduce_xla(f, op, xs, *, flags=None, offsets=None,
               "max": jax.ops.segment_max, "min": jax.ops.segment_min}
     ns = num_segments if offsets is None else offsets.shape[0] - 1
     if op.name in direct and isinstance(vals, jax.Array) and ns is not None:
-        seg_ids = (seg_k.flags_to_segment_ids(fl) if offsets is None else
+        seg_ids = (seg_k.flags_to_segment_ids(fl, backend="xla")
+                   if offsets is None else
                    jnp.searchsorted(offsets[1:], jnp.arange(vals.shape[0]),
                                     side="right"))
         return direct[op.name](vals, seg_ids, num_segments=ns)

@@ -1,53 +1,20 @@
-"""Version-compat shims for Pallas across the supported jax pins.
+"""One import point for the Pallas lowerings the kernels use.
 
-The per-lowering compiler-params dataclasses were renamed across JAX
-releases: ``TPUCompilerParams`` -> ``CompilerParams`` (Mosaic-TPU),
-``TritonCompilerParams`` -> ``CompilerParams`` (Triton), and
-``GPUCompilerParams`` -> ``CompilerParams`` (Mosaic-GPU).  Kernels import
-``pltpu`` / ``pltriton`` / ``plmgpu`` from here so they are written against
-the current names and still run on the 0.4.37 pin.
-
-:func:`gpu_compiler_params` builds Triton params tolerantly -- field names
-drift between pins, so unknown fields are dropped rather than raising --
-and returns ``None`` when no GPU lowering is importable at all, which
-``pl.pallas_call`` accepts (interpret-mode calls never consult it).
+Kernels import ``pl`` / ``pltpu`` / ``pltriton`` from here.
+:func:`gpu_compiler_params` builds the Triton params of the pallas-gpu
+kernels (interpret-mode calls never consult them).
 """
 from __future__ import annotations
 
 from jax.experimental import pallas as pl  # noqa: F401  (re-export)
 from jax.experimental.pallas import tpu as pltpu
-
-if not hasattr(pltpu, "CompilerParams"):  # pragma: no cover - version-dependent
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-try:
-    from jax.experimental.pallas import triton as pltriton
-except ImportError:  # pragma: no cover - pin without a Triton lowering
-    pltriton = None
-
-if pltriton is not None and not hasattr(pltriton, "CompilerParams"):
-    pltriton.CompilerParams = pltriton.TritonCompilerParams  # pragma: no cover
-
-try:
-    from jax.experimental.pallas import mosaic_gpu as plmgpu
-except ImportError:  # pragma: no cover - pin without Mosaic-GPU
-    plmgpu = None
-
-if (plmgpu is not None and not hasattr(plmgpu, "CompilerParams")
-        and hasattr(plmgpu, "GPUCompilerParams")):  # pragma: no cover
-    plmgpu.CompilerParams = plmgpu.GPUCompilerParams
+from jax.experimental.pallas import triton as pltriton
 
 
 def gpu_compiler_params(num_warps: int | None = None,
                         num_stages: int | None = None):
-    """Triton compiler params for ``pl.pallas_call``, or None without one."""
-    if pltriton is None:  # pragma: no cover - pin without a Triton lowering
-        return None
-    fields = getattr(pltriton.CompilerParams, "__dataclass_fields__", {})
-    kwargs = {k: v for k, v in
-              (("num_warps", num_warps), ("num_stages", num_stages))
-              if v is not None and k in fields}
-    return pltriton.CompilerParams(**kwargs)
+    """Triton compiler params for ``pl.pallas_call``."""
+    return pltriton.CompilerParams(num_warps=num_warps, num_stages=num_stages)
 
 
-__all__ = ["pl", "pltpu", "pltriton", "plmgpu", "gpu_compiler_params"]
+__all__ = ["pl", "pltpu", "pltriton", "gpu_compiler_params"]

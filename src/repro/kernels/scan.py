@@ -116,8 +116,7 @@ def _scan1d_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
         for cr, ic in zip(carry_refs, jax.tree.leaves(ident_carry)):
             cr[...] = ic
 
-    x = jax.tree.unflatten(
-        treedef, [xr[...].reshape(rows, ki.LANES) for xr in x_refs])
+    x = jax.tree.unflatten(treedef, [xr[...] for xr in x_refs])
 
     # Masked tail (vload_pattern analogue): OOB lanes read garbage; replace
     # with the operator identity so they cannot contaminate the carry.
@@ -133,7 +132,7 @@ def _scan1d_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
     for cr, nc in zip(carry_refs, jax.tree.leaves(new_carry)):
         cr[...] = nc
     for orf, o in zip(o_refs, jax.tree.leaves(out)):
-        orf[...] = o.reshape(-1)
+        orf[...] = o
 
 
 def scan_1d_pallas(op, xs: Pytree, *, inclusive: bool = True,
@@ -145,24 +144,26 @@ def scan_1d_pallas(op, xs: Pytree, *, inclusive: bool = True,
     n = leaves[0].shape[0]
     assert all(l.shape == (n,) for l in leaves), "1d scan: uniform leaf shapes"
     sub = max(ki.min_tile(l.dtype)[0] for l in leaves)
-    rows = policy.nitem_scan * sub
-    block = rows * ki.LANES
-    grid = ki.cdiv(n, block)
+    views = [ki.lane_rows(l, policy.nitem_scan * sub, sub) for l in leaves]
+    rows = views[0][1]
+    m = views[0][0].shape[0]
 
     kernel = functools.partial(
         _scan1d_kernel, op, treedef, n, rows, inclusive, len(leaves))
+    spec = pl.BlockSpec((rows, ki.LANES), lambda g: (g, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((block,), lambda g: (g,)) for _ in leaves],
-        out_specs=[pl.BlockSpec((block,), lambda g: (g,)) for _ in leaves],
-        out_shape=[jax.ShapeDtypeStruct((n,), l.dtype) for l in leaves],
+        grid=(ki.cdiv(m, rows),),
+        in_specs=[spec for _ in leaves],
+        out_specs=[spec for _ in leaves],
+        out_shape=[jax.ShapeDtypeStruct((m, ki.LANES), l.dtype)
+                   for l in leaves],
         scratch_shapes=[pltpu.VMEM((1, 1), l.dtype) for l in leaves],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*leaves)
-    return jax.tree.unflatten(treedef, out)
+    )(*[v for v, _ in views])
+    return jax.tree.unflatten(treedef, [ki.from_lane_rows(o, n) for o in out])
 
 
 # ---------------------------------------------------------------------------

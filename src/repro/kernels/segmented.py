@@ -78,9 +78,8 @@ def _segscan1d_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
         for cr, ic in zip(cv_refs, jax.tree.leaves(ident_carry[1])):
             cr[...] = ic
 
-    flags = f_ref[...].reshape(rows, ki.LANES)
-    vals = jax.tree.unflatten(
-        treedef, [xr[...].reshape(rows, ki.LANES) for xr in x_refs])
+    flags = f_ref[...]
+    vals = jax.tree.unflatten(treedef, [xr[...] for xr in x_refs])
 
     # Masked tail: out-of-bounds positions become the lifted identity
     # (flag 0, value identity) so they cannot contaminate the carry.
@@ -135,7 +134,7 @@ def _segscan1d_kernel(op, treedef, n, rows, inclusive, n_leaves, *refs):
     for cr, nc in zip(cv_refs, jax.tree.leaves(new_carry[1])):
         cr[...] = nc
     for orf, o in zip(o_refs, jax.tree.leaves(out)):
-        orf[...] = o.reshape(-1)
+        orf[...] = o
 
 
 def segmented_scan_1d_pallas(op, xs: Pytree, flags: jax.Array, *,
@@ -155,27 +154,29 @@ def segmented_scan_1d_pallas(op, xs: Pytree, flags: jax.Array, *,
     assert all(l.shape == (n,) for l in leaves), "segmented scan: uniform leaves"
     assert flags.shape == (n,), "flags must match the scanned extent"
     flags = flags.astype(jnp.int32)
-    sub = max(ki.min_tile(l.dtype)[0] for l in leaves)
-    rows = policy.nitem_scan * sub
-    block = rows * ki.LANES
-    grid = ki.cdiv(n, block)
+    sub = max(ki.min_tile(l.dtype)[0] for l in [flags, *leaves])
+    views = [ki.lane_rows(l, policy.nitem_scan * sub, sub)
+             for l in [flags, *leaves]]
+    rows = views[0][1]
+    m = views[0][0].shape[0]
 
     kernel = functools.partial(
         _segscan1d_kernel, op, treedef, n, rows, inclusive, len(leaves))
+    spec = pl.BlockSpec((rows, ki.LANES), lambda g: (g, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((block,), lambda g: (g,))
-                  for _ in range(1 + len(leaves))],
-        out_specs=[pl.BlockSpec((block,), lambda g: (g,)) for _ in leaves],
-        out_shape=[jax.ShapeDtypeStruct((n,), l.dtype) for l in leaves],
+        grid=(ki.cdiv(m, rows),),
+        in_specs=[spec for _ in views],
+        out_specs=[spec for _ in leaves],
+        out_shape=[jax.ShapeDtypeStruct((m, ki.LANES), l.dtype)
+                   for l in leaves],
         scratch_shapes=([pltpu.VMEM((1, 1), jnp.int32)] +
                         [pltpu.VMEM((1, 1), l.dtype) for l in leaves]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(flags, *leaves)
-    return jax.tree.unflatten(treedef, out)
+    )(*[v for v, _ in views])
+    return jax.tree.unflatten(treedef, [ki.from_lane_rows(o, n) for o in out])
 
 
 # ---------------------------------------------------------------------------
@@ -191,12 +192,15 @@ def offsets_to_flags(offsets: jax.Array, n: int) -> jax.Array:
     return flags.at[offsets[:-1]].set(1, mode="drop").at[0].set(1)
 
 
-def flags_to_segment_ids(flags: jax.Array) -> jax.Array:
-    """0-based contiguous segment id per element (element 0 starts seg 0)."""
+def flags_to_segment_ids(flags: jax.Array, *, backend: str | None = None,
+                         policy=None) -> jax.Array:
+    """0-based contiguous segment id per element (element 0 starts seg 0),
+    as a +scan of the flags on ``backend``'s own ``scan@flat`` (XLA's cumsum
+    at millions of elements takes the TPU compiler seconds)."""
     if flags.shape[0] == 0:
         return jnp.zeros((0,), jnp.int32)
     f = flags.astype(jnp.int32).at[0].set(1)
-    return jnp.cumsum(f) - 1
+    return ki.resolve_impl("scan@flat", backend)(alg.ADD, f, policy=policy) - 1
 
 
 def gather_segment_lasts(op, incl: Pytree, *,

@@ -79,26 +79,63 @@ def _key_bits_for(keys, key_bits):
 # ---------------------------------------------------------------------------
 
 
-def _radix_pass(bits, payloads, shift, digit_bits, backend, policy):
-    n = bits.shape[0]
-    n_buckets = 1 << digit_bits
+# Bytes of the int32 (elements x buckets) one-hot slab a radix pass holds at
+# once.  The stream is ranked one slab at a time, so a pass's temporaries
+# stay bounded whatever n is: a whole-stream one-hot of 8 x 256000 sampler
+# logits at 256 buckets would take 2 GiB, and its rank scan 2 GiB more.
+_RANK_SLAB_BYTES = 64 << 20
+
+
+def _digit_ranks(digit, n_buckets, backend, policy):
+    """Per-element stable rank within its bucket, and the bucket histogram.
+
+    The stream is cut into slabs; inside a slab the rank is an exclusive
+    +scan along the element axis with the buckets on the lanes ((1, s, R)
+    channel layout) and the slab histogram is a mapreduce over the same
+    one-hot; the histogram of the slabs before it, carried across the
+    slabs, turns slab ranks into stream ranks.
+    """
+    n = digit.shape[0]
     scan = ki.resolve_impl("scan@flat", backend)
     mapreduce = ki.resolve_impl("mapreduce@flat", backend)
+    # Buckets ride the lanes, padded to whole 128-lane rows in memory.
+    slab = min(n, max(1, _RANK_SLAB_BYTES
+                      // (4 * ki.round_up(n_buckets, ki.LANES))))
+    n_slabs = ki.cdiv(n, slab)
+    buckets = jnp.arange(n_buckets, dtype=jnp.int32)
+
+    def one_slab(before, d):
+        onehot = (d[:, None] == buckets[None, :]).astype(jnp.int32)
+        rank = scan(alg.ADD, onehot[None], axis=1, inclusive=False,
+                    policy=policy)[0]
+        dc = jnp.clip(d, 0, n_buckets - 1)
+        rank = jnp.take_along_axis(rank, dc[:, None], axis=1)[:, 0]
+        hist = mapreduce(lambda v: v, alg.ADD, onehot, axis=0, policy=policy)
+        return before + hist, rank + before[dc]
+
+    zeros = jnp.zeros((n_buckets,), jnp.int32)
+    if n_slabs == 1:
+        # No loop for one slab: called outside jit, a lax.scan would be
+        # compiled anew on every call.
+        hist, rank = one_slab(zeros, digit)
+        return rank, hist
+    # Padding digits match no bucket: they add nothing to any count.
+    padded = jnp.pad(digit, (0, n_slabs * slab - n), constant_values=-1)
+    hist, rank = jax.lax.scan(one_slab, zeros, padded.reshape(n_slabs, slab))
+    return rank.reshape(-1)[:n], hist
+
+
+def _radix_pass(bits, payloads, shift, digit_bits, backend, policy):
+    n_buckets = 1 << digit_bits
+    scan = ki.resolve_impl("scan@flat", backend)
 
     digit = jnp.right_shift(bits, jnp.asarray(shift, bits.dtype))
     digit = (digit & _full_mask(digit_bits, bits.dtype)).astype(jnp.int32)
-    onehot = (digit[:, None] ==
-              jnp.arange(n_buckets, dtype=jnp.int32)[None, :]).astype(jnp.int32)
-
-    # Within-bucket stable rank: exclusive +scan along the element axis,
-    # buckets on the lanes ((1, n, R) channel layout).
-    rank = scan(alg.ADD, onehot[None], axis=1, inclusive=False,
-                policy=policy)[0]
-    # Per-digit histogram and its exclusive scan = each bucket's base offset.
-    hist = mapreduce(lambda v: v, alg.ADD, onehot, axis=0, policy=policy)
+    rank, hist = _digit_ranks(digit, n_buckets, backend, policy)
+    # Exclusive scan of the histogram = each bucket's base offset.
     base = scan(alg.ADD, hist, inclusive=False, policy=policy)
 
-    dest = base[digit] + jnp.take_along_axis(rank, digit[:, None], axis=1)[:, 0]
+    dest = base[digit] + rank
     out_bits = jnp.zeros_like(bits).at[dest].set(bits, unique_indices=True)
     out_payloads = tuple(
         jnp.zeros_like(p).at[dest].set(p, unique_indices=True)
@@ -229,7 +266,7 @@ def _segment_ids_and_starts(n, flags, offsets, backend, policy):
     else:
         f = flags.astype(jnp.int32)
         s_bound = n  # static bound: at most one segment per element
-    seg_ids = seg_k.flags_to_segment_ids(f)
+    seg_ids = seg_k.flags_to_segment_ids(f, backend=backend, policy=policy)
     iota = jnp.arange(n, dtype=jnp.int32)
     flagged = jnp.where((f != 0) | (iota == 0), iota, -1)
     starts = scan(alg.MAX, flagged, policy=policy)
@@ -334,7 +371,8 @@ def segmented_top_k_radix(keys, k, *, flags=None, offsets=None,
         num_segments = int(offsets.shape[0]) - 1
         offs = offsets.astype(jnp.int32)
     else:
-        seg_ids = seg_k.flags_to_segment_ids(flags.astype(jnp.int32))
+        seg_ids = seg_k.flags_to_segment_ids(flags, backend=backend,
+                                             policy=policy)
         counts = jnp.zeros((num_segments,), jnp.int32).at[seg_ids].add(
             1, mode="drop")
         csum = scan(alg.ADD, counts, policy=policy)

@@ -179,8 +179,12 @@ def unembed(params, x, softcap=0.0):
     table = params.get("unembed")
     if table is None:
         table = params["embedding"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, table.astype(x.dtype))
-    logits = logits.astype(jnp.float32)
+    # Accumulate and keep the logits in f32: rounded to bf16, the top of a
+    # large vocabulary ties, and XLA's matrix-vector form of this dot (one
+    # row) and its matrix form (several rows) round differently, which
+    # flips greedy tokens between batch shapes.
+    logits = jnp.einsum("bsd,dv->bsv", x, table.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
     if softcap:
         logits = softcap * jnp.tanh(logits / softcap)
     return shard(logits, "batch", "seq_sp", "vocab")

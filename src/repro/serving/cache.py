@@ -59,6 +59,17 @@ def scatter_slot(live, single, slot):
     return out
 
 
+def stack_slots(singles):
+    """Stack batch=1 cache trees into one full-batch tree, slot ``i`` from
+    ``singles[i]``; the slot axes are those :func:`scatter_slot` writes."""
+    out = dict(singles[0])
+    for part, axis in (("prefix", 0), ("suffix", 0), ("units", 1)):
+        out[part] = jax.tree.map(
+            lambda *s, axis=axis: jnp.concatenate(s, axis),
+            *[c[part] for c in singles])
+    return out
+
+
 def select_slots(mask, new, old):
     """Per-slot select between two full-batch cache trees.
 

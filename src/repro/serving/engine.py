@@ -29,7 +29,7 @@ prefill admission, the loop *condition* (any-active / budget /
 stop-on-free), the transfer-guard dispatch seam, and stats.
 
 **Padded (the reference oracle, ``generate_padded``)** -- the original
-fixed-batch host loop: one prefill over the left-padded batch, one decode
+fixed-batch host loop: a prefill of each left-padded row, one decode
 dispatch + host sync per token.  It stays as the differential oracle for the
 parity suite (tests/test_serving_parity.py): same requests, same seeds =>
 identical token streams.  It is a *vanilla-sampling* oracle and refuses to
@@ -127,10 +127,14 @@ class Engine:
             top_p=self.top_p, top_p_candidates=self.top_p_candidates)
         self.strategy.bind(self)
         self._strategy_params = self.strategy.loop_params(self)
-        self._admit_fn = jax.jit(self._admit_impl)
+        # The state (caches included) is donated: every caller rebinds it
+        # to the result, and at full size a second copy of the caches does
+        # not fit beside the weights.
+        self._admit_fn = jax.jit(self._admit_impl, donate_argnums=(0,))
         self._loop_fn = {
             stop_on_free: jax.jit(functools.partial(
-                self._loop_impl, stop_on_free=stop_on_free))
+                self._loop_impl, stop_on_free=stop_on_free),
+                donate_argnums=(2,))
             for stop_on_free in (False, True)}
         self.last_stats: dict = {}
         self.last_scores = np.zeros((0,), np.float32)
@@ -469,10 +473,16 @@ class Engine:
         toks = np.zeros((B, plen), np.int32)
         for i, r in enumerate(requests):
             toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
-        batch = self._make_batch(toks)
-
+        # Each row is prefilled alone, as admission prefills a request: on
+        # a TPU, XLA compiles a prefill differently for different numbers
+        # of rows, and a row of a batched prefill rounds differently from
+        # the same row alone.  The rows are decoded together.
         t0 = time.time()
-        logits, caches = self._prefill(self.params, batch)
+        rows = [self._prefill(self.params, self._make_batch(toks[i:i + 1]))
+                for i in range(B)]
+        logits = jnp.concatenate([lg for lg, _ in rows])
+        caches = CA.stack_slots([c for _, c in rows])
+        del rows
         prefill_s = time.time() - t0
 
         max_new = max(r.max_new_tokens for r in requests)

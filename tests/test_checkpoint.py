@@ -77,6 +77,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs import base as C
 from repro.distributed import sharding as SH
 from repro.training import checkpoint as CKPT, optimizer as OPT, train_step as TS
+from repro.launch.mesh import make_mesh
 
 cfg = C.get_config("gemma2-27b", smoke=True)
 tc = TS.TrainConfig(optimizer=OPT.OptimizerConfig())
@@ -84,11 +85,11 @@ state = TS.init_state(jax.random.PRNGKey(0), cfg, tc)
 ckpt_dir = sys.argv[2]
 
 # Save from a (4 data x 2 model) mesh...
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
 CKPT.save(ckpt_dir, 5, state)
 
 # ...restore onto a (2 data x 4 model) mesh: elastic resharding on load.
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 shape = jax.eval_shape(lambda: state)
 specs = TS.state_specs(shape, cfg, mesh_b)
 shardings = SH.named(mesh_b, specs)

@@ -22,6 +22,7 @@ from repro.configs import base as C
 from repro.distributed import sharding as SH
 from repro.models import lm
 from repro.training import train_step as TS
+from repro.launch.mesh import make_mesh
 
 from repro.models import layers as L
 
@@ -30,7 +31,7 @@ from repro.models import layers as L
 for arch in ["gemma3-4b", "deepseek-v3-671b"]:
     cfg = C.get_config(arch, smoke=True)
     cfg = dataclasses.replace(cfg, dtype="float32", capacity_factor=16.0)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     B, S = 2, 16
     cache_len = 32  # divisible by model axis

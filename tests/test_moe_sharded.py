@@ -16,13 +16,14 @@ from repro.configs import base as C
 from repro.distributed import sharding as SH
 from repro.distributed.moe_sharded import moe_forward_sharded
 from repro.models import moe as M, layers as L
+from repro.launch.mesh import make_mesh
 
 cfg = C.get_config("moonshot-v1-16b-a3b", smoke=True)
 # High capacity: per-shard capacity rounding must not drop tokens in the
 # parity check (drop policy intentionally differs: global vs per-dp-shard).
 cfg = dataclasses.replace(cfg, dtype="float32", capacity_factor=16.0,
                           n_experts=8, moe_top_k=2)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 params = M.init_moe(key, cfg)
 B, S = 4, 8

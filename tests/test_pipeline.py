@@ -12,8 +12,9 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import gpipe_forward
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 key = jax.random.PRNGKey(0)
 n_stages, d = 2, 32
 Ws = jax.random.normal(key, (n_stages, d, d), jnp.float32) / np.sqrt(d)

@@ -40,6 +40,7 @@ from repro.core import operators as alg
 from repro.core import primitives as forge
 from repro.core.layout import Sharded
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +190,7 @@ def test_overlap_smoke_chunked_collectives(monkeypatch):
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("shard",))
+    return make_mesh((1,), ("shard",))
 
 
 def test_one_device_mesh_degenerate():
@@ -220,7 +221,6 @@ def test_one_device_mesh_degenerate():
 
 def test_in_mesh_form_inside_shard_map():
     """Sharded(axis) with mesh=None composes inside an existing shard_map."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh1()
@@ -232,8 +232,8 @@ def test_in_mesh_form_inside_shard_map():
                             layout=Sharded("shard"), backend="xla")
         return s, t
 
-    s, t = shard_map(local, mesh=mesh, in_specs=(P("shard"),),
-                     out_specs=(P("shard"), P()), check_rep=False)(x)
+    s, t = jax.shard_map(local, mesh=mesh, in_specs=(P("shard"),),
+                         out_specs=(P("shard"), P()), check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(s), np.cumsum(np.arange(12.0)),
                                rtol=1e-6)
     np.testing.assert_allclose(float(t), 66.0, rtol=1e-6)
@@ -270,6 +270,7 @@ from repro.core import operators as alg
 from repro.core import primitives as forge
 from repro.core.layout import Sharded
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 
 def close(a, b, tol=1e-5, err=""):
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
@@ -282,8 +283,8 @@ def exact(a, b, err=""):
                                       err_msg=err)
 
 nprng = np.random.default_rng(17)
-mesh8 = jax.make_mesh((8,), ("shard",))
-mesh1 = jax.make_mesh((1, 8), ("one", "shard"))  # degenerate axis alongside
+mesh8 = make_mesh((8,), ("shard",))
+mesh1 = make_mesh((1, 8), ("one", "shard"))  # degenerate axis alongside
 lo8 = Sharded("shard", mesh=mesh8)
 lo1 = Sharded("one", mesh=mesh1)
 """
@@ -406,7 +407,6 @@ CONSUMERS_SCRIPT = _SCRIPT_PRELUDE + r"""
 # -- merge_partials dispatches through mapreduce@sharded and still equals
 #    the SOFTMAX_MERGE operator fold (the test_flash_decode.py equivalence
 #    assertion, now exercised through the real 8-device collective) --------
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed import collectives as coll
 
@@ -418,10 +418,10 @@ o = jax.random.normal(ks[2], (S, 4, 16), jnp.float32)
 parts = [(m[i], l[i], o[i]) for i in range(S)]
 fm, fl, fo = functools.reduce(alg.SOFTMAX_MERGE, parts)
 want = fo / fl[..., None]
-merged = shard_map(
+merged = jax.shard_map(
     lambda mm, ll, oo: coll.merge_partials(mm[0], ll[0], oo[0], "shard"),
     mesh=mesh8, in_specs=(P("shard"), P("shard"), P("shard")),
-    out_specs=P(), check_rep=False)(m, l, o)
+    out_specs=P(), check_vma=False)(m, l, o)
 np.testing.assert_allclose(np.asarray(merged), np.asarray(want),
                            rtol=1e-5, atol=1e-5)
 print("merge_partials == SOFTMAX_MERGE fold OK", flush=True)
@@ -429,7 +429,7 @@ print("merge_partials == SOFTMAX_MERGE fold OK", flush=True)
 # -- all-masked-row regression: an all-padding request through
 #    flash_decode_gqa must yield exact zeros, even with poisoned (NaN)
 #    cache slots -- not 0/1e-30 garbage ------------------------------------
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 B, L, K, G, hd = 2, 32, 2, 2, 8
 q = jax.random.normal(jax.random.PRNGKey(0), (B, 1, K, G, hd), jnp.float32)
 k_cache = jnp.full((B, L, K, hd), jnp.nan, jnp.float32)   # uninitialized

@@ -10,6 +10,7 @@ from repro.core import operators as alg
 from repro.core import primitives as forge
 from repro.core import tuning
 from repro.core.layout import Batched, Segmented
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture
@@ -155,7 +156,7 @@ def test_sharded_keys_carry_mesh_topology(tuner):
     1-device winner is never replayed on an N-device mesh."""
     import jax
     from repro.core.layout import Sharded
-    mesh = jax.make_mesh((1,), ("shard",))
+    mesh = make_mesh((1,), ("shard",))
     x = jnp.arange(512, dtype=jnp.float32)
     got = forge.scan(alg.ADD, x, layout=Sharded("shard", mesh=mesh),
                      backend="pallas-interpret")
@@ -184,3 +185,42 @@ def test_sort_ladder_races_digit_width(tuner):
                                                       "nitem_scan"}
     np.testing.assert_array_equal(np.asarray(got),
                                   np.sort(np.asarray(k), kind="stable"))
+
+
+@pytest.mark.parametrize("kind,chip,policy", [
+    ("TPU v5 lite", "tpu_v5e", "tpu_v5e"),
+    ("TPU v5", "tpu_v5p", "tpu_v5p"),
+    ("TPU v6 lite", "tpu_v6_lite", "generic"),
+    ("TPU v4", "tpu_v4", "generic"),
+])
+def test_detect_chip_names_the_tpu_it_sees(monkeypatch, kind, chip, policy):
+    """An unknown TPU is named after its device_kind and gets the generic
+    policy -- never another chip's."""
+    import types
+
+    import jax
+    from repro.core import intrinsics as ki
+    dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+    assert ki.detect_chip() == chip
+    assert ki.resolve_tuning().name == policy
+
+
+def test_compile_cache_dir_rule(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes
+    to the fixed .jax_cache at the root of the checkout."""
+    import pathlib
+
+    import jax
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert CC.enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

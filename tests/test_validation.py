@@ -13,6 +13,7 @@ from repro.core import intrinsics as ki
 from repro.core import operators as alg
 from repro.core import primitives as forge
 from repro.core.layout import Batched, Flat, Segmented, Sharded
+from repro.launch.mesh import make_mesh
 
 X = jnp.arange(8, dtype=jnp.float32)
 FLAGS = jnp.ones((8,), jnp.int32)
@@ -174,7 +175,7 @@ def test_layout_must_be_a_descriptor():
 
 def test_sharded_axis_must_name_a_mesh_axis():
     import jax
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with _raises(r"scan@sharded: axis 'nope' is not an axis of the mesh "
                  r"\(axes: \('model',\)\)"):
         forge.scan(alg.ADD, X, layout=Sharded("nope", mesh=mesh),

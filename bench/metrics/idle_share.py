@@ -1,0 +1,11 @@
+"""Device: share of the traced window in which no operation ran on the
+device (1 - union of device op intervals / window), averaged over the
+chips used."""
+UNIT, SOURCE, LAYER, MOVES = "%", "device_trace", "device", "tokens_per_s"
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)

@@ -1,0 +1,8 @@
+"""bench/tests run by hand: ``python -m pytest bench/tests`` (the
+repository's ``pytest.ini`` collects ``tests/`` only)."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
+sys.path.insert(0, BENCH)

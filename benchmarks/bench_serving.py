@@ -58,7 +58,6 @@ def run_continuous(eng, arrivals):
         "decode_steps": st["decode_steps"],
         "loop_dispatches": st["loop_dispatches"],
         "admissions": st["admissions"],
-        "prefill_s": st["prefill_s"],
         "decode_s": st["decode_s"],
         "latency_steps": {
             "p50": float(np.percentile(lat_steps, 50)),

@@ -285,8 +285,7 @@ def serving(key, seed, *, cfg, cache_len, batch, short, long, max_new,
         n_k = _loop_kernels(eng, backend, "greedy")
         print(f"  generate greedy: {batch} requests (prompts {short}/{long}, "
               f"max_new {max_new}) == generate_padded, repeatable; "
-              f"loop kernels={n_k} prefill_s={stats['prefill_s']!r} "
-              f"decode_s={stats['decode_s']!r} "
+              f"loop kernels={n_k} decode_s={stats['decode_s']!r} "
               f"s={time.perf_counter() - t0!r}", flush=True)
         del eng
 

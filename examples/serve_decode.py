@@ -52,8 +52,7 @@ def main():
     for i, o in enumerate(outs):
         print(f"  req{i}: {o[:12]}{'...' if len(o) > 12 else ''}")
     s = eng.last_stats
-    print(f"[serve] prefill {s['prefill_s']*1e3:.1f}ms, decode "
-          f"{s['decode_tok_per_s']:.1f} tok/s (host CPU), "
+    print(f"[serve] decode {s['decode_tok_per_s']:.1f} tok/s (host CPU), "
           f"{s['loop_dispatches']} device-loop dispatch(es), wall {dt:.2f}s")
 
     # Open-loop traffic: 3x more requests than slots arriving over time;

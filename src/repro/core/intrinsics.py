@@ -901,32 +901,36 @@ def dispatch(primitive: str, layout, backend: str | None,
     """
     layout = lay.as_layout(layout)
     route = get_route(primitive, layout.kind)
-    kwargs = dict(kwargs)
-    _validate(route, layout, args, kwargs)
-    if route.needs_descriptor:
-        kwargs["flags"] = layout.flags
-        kwargs["offsets"] = layout.offsets
-        if route.needs_num_segments:
-            kwargs["num_segments"] = layout.num_segments
-    if route.needs_mesh:
-        kwargs["axis_name"] = layout.axis
-        kwargs["mesh"] = layout.mesh
-        kwargs["overlap"] = layout.overlap
-    if route.zero_extent is not None:
-        handled, result = _ZERO_GUARDS[route.zero_extent](route, args, kwargs)
-        if handled:
-            return result
-    if route.noncomm_route is not None and not getattr(
-            args[route.op_arg], "commutative", False):
-        # Order-preserving reroute: scan the mapped values with the same
-        # layout, take each problem's last element.  (Registered scans are
-        # order-preserving, so the batched family relaxes mapreduce's
-        # commutativity contract for free.)
-        f, op, xs = args[0], args[1], args[2]
-        incl = resolve_impl(route.noncomm_route, backend)(
-            op, f(xs), inclusive=True)
-        return jax.tree.map(lambda l: l[:, -1], incl)
-    return resolve_impl(route.key, backend)(*args, **kwargs)
+    # Every op of the route carries its name in its op_name, nested as the
+    # calls nest ("top_k.segmented": an "@" would cut the compiled op_name).
+    with jax.named_scope(f"{primitive}.{layout.kind}"):
+        kwargs = dict(kwargs)
+        _validate(route, layout, args, kwargs)
+        if route.needs_descriptor:
+            kwargs["flags"] = layout.flags
+            kwargs["offsets"] = layout.offsets
+            if route.needs_num_segments:
+                kwargs["num_segments"] = layout.num_segments
+        if route.needs_mesh:
+            kwargs["axis_name"] = layout.axis
+            kwargs["mesh"] = layout.mesh
+            kwargs["overlap"] = layout.overlap
+        if route.zero_extent is not None:
+            handled, result = _ZERO_GUARDS[route.zero_extent](
+                route, args, kwargs)
+            if handled:
+                return result
+        if route.noncomm_route is not None and not getattr(
+                args[route.op_arg], "commutative", False):
+            # Order-preserving reroute: scan the mapped values with the
+            # same layout, take each problem's last element.  (Registered
+            # scans are order-preserving, so the batched family relaxes
+            # mapreduce's commutativity contract for free.)
+            f, op, xs = args[0], args[1], args[2]
+            incl = resolve_impl(route.noncomm_route, backend)(
+                op, f(xs), inclusive=True)
+            return jax.tree.map(lambda l: l[:, -1], incl)
+        return resolve_impl(route.key, backend)(*args, **kwargs)
 
 
 # -- the table itself -------------------------------------------------------

@@ -116,9 +116,16 @@ class Engine:
                 "vanilla oracle only")
         self.prefill_buckets = self._resolve_buckets(prefill_buckets)
         self._base_key = jax.random.PRNGKey(seed)
-        self._prefill = jax.jit(
+        # The programs are jitted as named functions (never partials), so a
+        # profiler trace names them jit_prefill, jit_admit, jit_decode_loop.
+        prefill_step = (
             TS.make_prefill_step(cfg, mesh, cache_len) if mesh is not None
             else functools.partial(self._plain_prefill, cache_len=cache_len))
+
+        def prefill(params, batch):
+            return prefill_step(params, batch)
+
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(
             TS.make_decode_step(cfg, mesh) if mesh is not None
             else lambda p, c, t, pos: lm.decode_step(p, cfg, c, t, pos))
@@ -130,12 +137,19 @@ class Engine:
         # The state (caches included) is donated: every caller rebinds it
         # to the result, and at full size a second copy of the caches does
         # not fit beside the weights.
-        self._admit_fn = jax.jit(self._admit_impl, donate_argnums=(0,))
-        self._loop_fn = {
-            stop_on_free: jax.jit(functools.partial(
-                self._loop_impl, stop_on_free=stop_on_free),
-                donate_argnums=(2,))
-            for stop_on_free in (False, True)}
+        def admit(state, *args):
+            return self._admit_impl(state, *args)
+
+        def loop_program(stop_on_free):
+            def decode_loop(params, sparams, state, budget):
+                return self._loop_impl(params, sparams, state, budget,
+                                       stop_on_free=stop_on_free)
+            return jax.jit(decode_loop, donate_argnums=(2,))
+
+        self._admit_fn = jax.jit(admit, donate_argnums=(0,))
+        self._loop_fn = {stop_on_free: loop_program(stop_on_free)
+                         for stop_on_free in (False, True)}
+        self._host_syncs = 0
         self.last_stats: dict = {}
         self.last_scores = np.zeros((0,), np.float32)
 
@@ -334,9 +348,9 @@ class Engine:
         sched = Scheduler(self.batch_size)
         state = self._fresh_state()
         now = 0
-        stats = {"loop_dispatches": 0, "decode_steps": 0, "prefill_s": 0.0,
-                 "decode_s": 0.0, "admissions": 0}
-        t_serve = time.time()
+        stats = {"loop_dispatches": 0, "decode_steps": 0, "decode_s": 0.0,
+                 "admissions": 0}
+        self._host_syncs = 0
 
         def submit_due():
             while pending and pending[-1][0] <= now:
@@ -351,20 +365,20 @@ class Engine:
                 if r.max_new_tokens < 1:
                     sched.complete(rec.slot, step=now)
                     continue
-                t0 = time.time()
-                toks, vlen = self._pad_prompt(r.prompt)
-                logits1, caches1 = self._prefill(
-                    self.params, self._make_batch(toks, valid_len=vlen))
-                extras = self.strategy.host_prefill(self, toks, vlen)
-                pos0 = len(r.prompt) + self.cfg.num_prefix_embeds
-                state = self._admit_fn(
-                    state, caches1, logits1, extras,
-                    jnp.asarray(rec.slot, jnp.int32),
-                    jnp.asarray(rec.seed, jnp.int32),
-                    jnp.asarray(r.max_new_tokens, jnp.int32),
-                    jnp.asarray(r.eos_id, jnp.int32),
-                    jnp.asarray(pos0, jnp.int32))
-                stats["prefill_s"] += time.time() - t0
+                with jax.profiler.TraceAnnotation("admit",
+                                                  request_id=rec.rid):
+                    toks, vlen = self._pad_prompt(r.prompt)
+                    logits1, caches1 = self._prefill(
+                        self.params, self._make_batch(toks, valid_len=vlen))
+                    extras = self.strategy.host_prefill(self, toks, vlen)
+                    pos0 = len(r.prompt) + self.cfg.num_prefix_embeds
+                    state = self._admit_fn(
+                        state, caches1, logits1, extras,
+                        jnp.asarray(rec.slot, jnp.int32),
+                        jnp.asarray(rec.seed, jnp.int32),
+                        jnp.asarray(r.max_new_tokens, jnp.int32),
+                        jnp.asarray(r.eos_id, jnp.int32),
+                        jnp.asarray(pos0, jnp.int32))
                 stats["admissions"] += 1
 
             live = sched.live_slots
@@ -384,14 +398,16 @@ class Engine:
             # -- one device-loop dispatch: run until all-done, bounded by the
             # next arrival event; break out early on a freed slot only when
             # someone is waiting for it.
-            budget = int(np.max(np.asarray(
+            budget = int(np.max(self._to_host(
                 state["max_new"] - state["emitted"]))) + 1
             if pending:
                 budget = max(1, min(budget, pending[-1][0] - now))
             stop_on_free = sched.has_waiting or bool(pending)
             t0 = time.time()
-            state, steps = self._dispatch_loop(state, budget, stop_on_free)
-            steps = int(steps)                     # control-plane sync only
+            with jax.profiler.TraceAnnotation("dispatch"):
+                state, steps = self._dispatch_loop(state, budget,
+                                                   stop_on_free)
+                steps = int(self._to_host(steps))  # control-plane sync only
             stats["decode_s"] += time.time() - t0
             stats["loop_dispatches"] += 1
             stats["decode_steps"] += steps
@@ -400,37 +416,48 @@ class Engine:
             state = self._drain_done(sched, state, now)
 
         recs = [sched.records[rid] for rid in sorted(sched.records)]
-        stats["serve_s"] = time.time() - t_serve
         n_tok = sum(len(rec.tokens) for rec in recs)
         stats["decode_tok_per_s"] = n_tok / max(stats["decode_s"], 1e-9)
         stats["seq_logprob"] = [rec.seq_logprob for rec in recs]
         stats["total_tokens"] = n_tok
-        stats["final_step"] = now
         stats.update(self.strategy.stats(self, state))
+        stats["host_syncs"] = self._host_syncs
         self.last_stats = stats
         self.last_scores = np.asarray(
             [rec.seq_logprob for rec in recs], np.float32)
         return recs
 
+    def _to_host(self, x) -> np.ndarray:
+        """Every device-to-host read ``serve`` makes goes through here;
+        ``last_stats["host_syncs"]`` counts them."""
+        self._host_syncs += 1
+        return np.asarray(x)
+
     def _drain_done(self, sched: Scheduler, state, now):
         """Evict finished slots: pull their ragged outputs (the only token
         sync -- at completion) through the CSR compaction descriptor."""
-        done_slots = [s for s in sched.live_slots
-                      if not bool(state["active"][s])]
-        if not done_slots:
-            return state
+        with jax.profiler.TraceAnnotation("drain"):
+            with jax.profiler.TraceAnnotation("drain.poll"):
+                done_slots = [s for s in sched.live_slots
+                              if not self._to_host(state["active"][s])]
+            if not done_slots:
+                return state
+            with jax.profiler.TraceAnnotation("drain.collect"):
+                return self._collect(sched, state, now, done_slots)
+
+    def _collect(self, sched: Scheduler, state, now, done_slots):
         outs = self.strategy.outputs(self, state)
         seq_lp = outs["seq_logprob"]
         flat, offsets = CA.compact_ragged(outs["out"], outs["emitted"])
-        flat = np.asarray(flat)
-        offsets = np.asarray(offsets)
+        flat = self._to_host(flat)
+        offsets = self._to_host(offsets)
         meta = outs.get("meta", {})
         for slot in done_slots:
             rec = sched.complete(slot, step=now)
             rec.tokens = [int(t) for t in flat[offsets[slot]:offsets[slot + 1]]]
-            rec.seq_logprob = float(seq_lp[slot])
+            rec.seq_logprob = float(self._to_host(seq_lp[slot]))
             for key, per_slot in meta.items():
-                rec.meta[key] = np.asarray(per_slot[slot]).item()
+                rec.meta[key] = self._to_host(per_slot[slot]).item()
             if self.poison_on_evict:
                 state = dict(state)
                 state["caches"] = self.strategy.poison(
@@ -477,13 +504,11 @@ class Engine:
         # a TPU, XLA compiles a prefill differently for different numbers
         # of rows, and a row of a batched prefill rounds differently from
         # the same row alone.  The rows are decoded together.
-        t0 = time.time()
         rows = [self._prefill(self.params, self._make_batch(toks[i:i + 1]))
                 for i in range(B)]
         logits = jnp.concatenate([lg for lg, _ in rows])
         caches = CA.stack_slots([c for _, c in rows])
         del rows
-        prefill_s = time.time() - t0
 
         max_new = max(r.max_new_tokens for r in requests)
         outputs = [[] for _ in range(B)]
@@ -534,7 +559,6 @@ class Engine:
         self.last_scores = np.asarray(seq_logprob)
 
         self.last_stats = {
-            "prefill_s": prefill_s,
             "decode_s": decode_s,
             "decode_tok_per_s": n_tok / max(decode_s, 1e-9),
             "seq_logprob": self.last_scores.tolist(),

@@ -76,31 +76,41 @@ def sample_tokens(base_key, logits, seeds, steps, *, temperature, top_k,
     -- in particular every candidate survives iff the renormalized
     exclusive prefix stays below ``top_p``, regardless of how little
     full-vocab mass the k candidates carry.
+
+    The ops run under the scope ``sampler``, and the candidate selection
+    under ``sampler/top_k``, whatever implements it: a profiler trace
+    attributes device time to them by name.
     """
-    if temperature <= 0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    keys = request_step_keys(base_key, seeds, steps)
-    B, V = logits.shape
-    if top_k or top_p < 1.0:
-        k = min(top_k if top_k else top_p_candidates, V)
-        flat = logits.astype(jnp.float32).reshape(-1)
-        offsets = jnp.arange(B + 1, dtype=jnp.int32) * V
-        vals, idx = forge.top_k(flat, k, layout=Segmented(offsets=offsets))
-        scaled = vals / temperature                   # (B, k) descending
-        # Keep the shortest prefix whose mass reaches top_p (the first
-        # candidate always survives: its exclusive prefix mass is 0).  The
-        # (B, k) candidate grid is exactly the batched-scan layout: one
-        # launch scans every request's row, whatever the batch size.
-        probs = jax.nn.softmax(scaled, axis=-1)
-        cum = forge.scan(alg.ADD, probs, inclusive=False, layout=Batched())
-        filtered = jnp.where(cum < top_p, scaled, -jnp.inf)
-        g = jax.vmap(lambda kk: jax.random.gumbel(kk, (k,), jnp.float32))(keys)
-        choice = jnp.argmax(filtered + g, axis=-1)
-        return jnp.take_along_axis(
-            idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
-    g = jax.vmap(lambda kk: jax.random.gumbel(kk, (V,), jnp.float32))(keys)
-    return jnp.argmax(logits.astype(jnp.float32) / temperature + g,
-                      axis=-1).astype(jnp.int32)
+    with jax.named_scope("sampler"):
+        if temperature <= 0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        keys = request_step_keys(base_key, seeds, steps)
+        B, V = logits.shape
+        if top_k or top_p < 1.0:
+            k = min(top_k if top_k else top_p_candidates, V)
+            flat = logits.astype(jnp.float32).reshape(-1)
+            offsets = jnp.arange(B + 1, dtype=jnp.int32) * V
+            with jax.named_scope("top_k"):
+                vals, idx = forge.top_k(
+                    flat, k, layout=Segmented(offsets=offsets))
+            scaled = vals / temperature               # (B, k) descending
+            # Keep the shortest prefix whose mass reaches top_p (the first
+            # candidate always survives: its exclusive prefix mass is 0).
+            # The (B, k) candidate grid is exactly the batched-scan layout:
+            # one launch scans every request's row, whatever the batch size.
+            probs = jax.nn.softmax(scaled, axis=-1)
+            cum = forge.scan(alg.ADD, probs, inclusive=False,
+                             layout=Batched())
+            filtered = jnp.where(cum < top_p, scaled, -jnp.inf)
+            g = jax.vmap(
+                lambda kk: jax.random.gumbel(kk, (k,), jnp.float32))(keys)
+            choice = jnp.argmax(filtered + g, axis=-1)
+            return jnp.take_along_axis(
+                idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
+        g = jax.vmap(
+            lambda kk: jax.random.gumbel(kk, (V,), jnp.float32))(keys)
+        return jnp.argmax(logits.astype(jnp.float32) / temperature + g,
+                          axis=-1).astype(jnp.int32)
 
 
 def masked_seq_logprobs(logps, emitted):

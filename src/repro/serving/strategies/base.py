@@ -56,7 +56,8 @@ class DecodeStrategy:
 
     def stats(self, eng, state) -> dict:
         """Strategy-specific entries merged into ``engine.last_stats`` at
-        the end of ``serve`` (host sync is fine here: serving is done)."""
+        the end of ``serve`` (host sync is fine here: serving is done;
+        read through ``eng._to_host`` so that ``host_syncs`` counts it)."""
         return {}
 
     # -- device-side, traced -------------------------------------------------

@@ -105,10 +105,10 @@ class Speculative(DecodeStrategy):
         return dft_caches1
 
     def stats(self, eng, state) -> dict:
-        prop = int(state["tot_prop"])
-        acc = int(state["tot_acc"])
+        prop = int(eng._to_host(state["tot_prop"]))
+        acc = int(eng._to_host(state["tot_acc"]))
         return {
-            "spec_rounds": int(state["tot_rounds"]),
+            "spec_rounds": int(eng._to_host(state["tot_rounds"])),
             "spec_proposed": prop,
             "spec_accepted": acc,
             "spec_acceptance_rate": acc / max(prop, 1),

@@ -389,7 +389,8 @@ def sort_bytes(n: int, dtype, policy=None, *, key_bits: int | None = None,
     tuning ladder's ``sort_digit_bits`` races exactly that trade-off, and
     shrinking the gap is the motivation for a future fused sort pass).
     Fewer significant ``key_bits`` (small-range keys like expert ids)
-    proportionally cut the pass count in both models.
+    proportionally cut the pass count in both models.  The top-k routes no
+    longer sort the stream (:func:`top_k_bytes`).
     """
     policy = policy or ki.resolve_tuning()
     sz = jnp.dtype(dtype).itemsize
@@ -405,11 +406,26 @@ def sort_bytes(n: int, dtype, policy=None, *, key_bits: int | None = None,
 
 def top_k_bytes(n: int, k: int, dtype, policy=None, *,
                 num_segments: int = 1) -> int:
-    """top-k = index-carrying sort + the (S, k) gather of the winners."""
+    """top-k by radix select: what selection must move.
+
+    * one read of the n keys per threshold pass, one pass per key bit (each
+      pass counts, per segment, the keys below a candidate threshold);
+    * one scan of the int32 survivor mask;
+    * the index-carrying sort of the S*k survivors, and the (S, k) output.
+
+    Honesty note: the portable composition counts through
+    ``mapreduce@segmented``, a segmented scan plus a gather, so each pass
+    also reads the segment ids, writes and re-reads the 0/1 mask and writes
+    the scan's n counts: about seven times this per-pass bound (one key
+    read).  A fused count kernel would keep all of that in VMEM.  No
+    ``(n, 2^digit_bits)`` array is made: the sort runs over S*k survivors.
+    """
     sz = jnp.dtype(dtype).itemsize
-    return (sort_bytes(n, dtype, policy, payload_itemsize=4,
+    cand = num_segments * k
+    return (8 * sz * n * sz + scan_bytes(n, [jnp.int32], policy) +
+            sort_bytes(cand, dtype, policy, payload_itemsize=4,
                        num_segments=num_segments) +
-            num_segments * k * (sz + 4))
+            cand * (sz + 4))
 
 
 def sharded_top_k_bytes(n: int, k: int, dtype, shards: int,

@@ -29,6 +29,11 @@ ever packing the pair into one word (so u32 keys plus any segment count
 compose).  Because segments are contiguous and the sort is stable, the
 output layout (segment boundaries) is identical to the input layout.
 
+Top-k selects rather than sorts: one-bit counting passes (a segmented
+``mapreduce`` each, most significant bit first) find every segment's k-th
+key, one ``scan`` places the survivors, and only the ``S * k`` survivors
+go through the radix sort.
+
 Total order (pinned in ``operators.key_to_radix_bits``): unsigned/signed
 ints numerically; floats numerically with ``-0.0 == +0.0`` and **all NaNs
 equal, sorting after +inf** (NaN-last ascending, NaN-first for
@@ -37,6 +42,7 @@ input order (LSD radix is stable).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -44,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.core import intrinsics as ki
 from repro.core import operators as alg
+from repro.core.layout import Segmented
 from repro.kernels import segmented as seg_k
 
 Pytree = Any
@@ -224,24 +231,6 @@ def argsort_radix(keys, *, descending=False, key_bits=None,
     return perm
 
 
-@ki.sub_backend_alias
-def top_k_radix(keys, k, *, largest=True, key_bits=None, backend="xla",
-                policy=None):
-    """(values, indices) of the k extreme elements, sorted, ties stable."""
-    n = keys.shape[0]
-    if not 0 <= k <= n:
-        raise ValueError(f"top_k: need 0 <= k <= n, got k={k}, n={n}")
-    policy = _resolve_policy(policy, backend)
-    kb = _key_bits_for(keys, key_bits)
-    if k == 0:
-        return keys[:0], jnp.zeros((0,), jnp.int32)
-    bits = _to_bits(keys, kb, largest)
-    iota = jnp.arange(n, dtype=jnp.int32)
-    bits, (idx,) = _radix_passes(bits, (iota,), kb, policy.sort_digit_bits,
-                                 backend, policy)
-    return _from_bits(bits[:k], keys.dtype, kb, largest), idx[:k]
-
-
 # ---------------------------------------------------------------------------
 # Segmented variants (PR 1 descriptors: flag array / CSR offsets).  The
 # descriptor-exclusivity and num_segments checks live in the registry's
@@ -350,6 +339,133 @@ def segmented_argsort_radix(keys, *, flags=None, offsets=None,
     return perm - starts
 
 
+# ---------------------------------------------------------------------------
+# Top-k by radix select.  A top-k needs each segment's k-th key, not the
+# order of the elements behind it: one-bit counting passes, most significant
+# bit first, narrow that key; what beats it, plus its first ties in index
+# order, survives; only the survivors are sorted.
+# ---------------------------------------------------------------------------
+
+
+def _ordered_i32(bits):
+    """int32 with the order of the unsigned radix ``bits``: Mosaic compares
+    int32, not uint32, so 32-bit words flip their top bit."""
+    if bits.dtype.itemsize < 4:
+        return bits.astype(jnp.int32)
+    top = jnp.asarray(1 << 31, bits.dtype)
+    return jax.lax.bitcast_convert_type(bits ^ top, jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "k", "num_segments", "largest", "kb", "backend", "policy"))
+def _select_top_k(keys, flags, offsets, *, k, num_segments, largest, kb,
+                  backend, policy):
+    """``(S, k)`` values and within-segment indices, extreme-first, ties in
+    index order; slots past a segment's length hold the identity and -1.
+
+    One program per shape, so an eager call compiles once.  Elements past
+    the ``num_segments``-th flagged run belong to no segment.
+    """
+    n = keys.shape[0]
+    S = num_segments
+    scan = ki.resolve_impl("scan@flat", backend)
+    if offsets is not None:
+        layout = Segmented(offsets=offsets)
+        offs = offsets.astype(jnp.int32)
+        # An element's segment is the number of segment ends at or before it.
+        ends = jnp.zeros((n,), jnp.int32).at[offs[1:]].add(1, mode="drop")
+        seg = scan(alg.ADD, ends, policy=policy)
+    else:
+        layout = Segmented(flags=flags, num_segments=S)
+        seg = seg_k.flags_to_segment_ids(flags, backend=backend,
+                                         policy=policy)
+        counts = jnp.zeros((S,), jnp.int32).at[seg].add(1, mode="drop")
+        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                scan(alg.ADD, counts, policy=policy)])
+    seg = jnp.minimum(seg, S - 1)
+    starts = offs[:-1]
+    want = jnp.minimum(offs[1:] - starts, k)
+    # The wanted elements are the ``want`` smallest bits of each segment.
+    bits = _to_bits(keys, kb, largest)
+    order = _ordered_i32(bits & _full_mask(kb, bits.dtype))
+
+    def count_below(thr):
+        """Per segment, the elements ordered below its threshold ``thr``."""
+        return ki.dispatch(
+            "mapreduce", layout, backend,
+            (lambda t: (t[0] < thr[t[1]]).astype(jnp.int32), alg.ADD,
+             (order, seg)), {"policy": policy})
+
+    def narrow(i, carry):
+        # ``t`` is a prefix of the want-th smallest bits of its segment and
+        # ``below`` counts the segment's elements under ``t``.
+        t, below = carry
+        shift = (kb - 1 - i).astype(bits.dtype)
+        cand = t | jnp.left_shift(jnp.asarray(1, bits.dtype), shift)
+        count = count_below(_ordered_i32(cand))
+        up = count < want
+        return jnp.where(up, cand, t), jnp.where(up, count, below)
+
+    t, below = jax.lax.fori_loop(
+        0, kb, narrow,
+        (jnp.zeros((S,), bits.dtype), jnp.zeros((S,), jnp.int32)))
+
+    # Survivors: every element below the segment's k-th bits, then the
+    # first ``want - below`` of its ties.  Counts over the whole stream rise
+    # by one at each element of a kind, so the j-th element of a kind in
+    # segment s sits where its count first reaches (its count before s) +
+    # j + 1: a binary search per (s, j) slot, never a pass over n.
+    thr = _ordered_i32(t)[seg]
+    lt, eq = ki.dispatch(
+        "scan", None, backend,
+        (alg.ADD, ((order < thr).astype(jnp.int32),
+                   (order == thr).astype(jnp.int32))), {"policy": policy})
+    j = jnp.arange(k, dtype=jnp.int32)[None, :]
+    prior = lambda c: jnp.where(  # noqa: E731
+        starts > 0, c[jnp.maximum(starts - 1, 0)], 0)[:, None]
+    pos = jnp.where(
+        j < below[:, None], jnp.searchsorted(lt, prior(lt) + j + 1),
+        jnp.searchsorted(eq, prior(eq) + j - below[:, None] + 1))
+    valid = j < want[:, None]
+    pos = jnp.clip(pos, 0, n - 1)
+    # Below-threshold survivors fill a row before its ties, each kind in
+    # index order, so the stable sort of the (S, k) grid by (segment, bits)
+    # orders ties by index.  Empty slots take the largest bits: last.
+    cand = jnp.where(valid, bits[pos], _full_mask(kb, bits.dtype))
+    idx = jnp.where(valid, pos - starts[:, None], -1)
+    rows = jnp.repeat(jnp.arange(S, dtype=jnp.uint32), k)
+    cand, (rows, idx) = _radix_passes(
+        cand.reshape(-1), (rows, idx.reshape(-1)), kb,
+        policy.sort_digit_bits, backend, policy)
+    seg_bits = (S - 1).bit_length()
+    if seg_bits:
+        _, (cand, idx) = _radix_passes(rows, (cand, idx), seg_bits,
+                                       policy.sort_digit_bits, backend,
+                                       policy)
+    fill = jnp.asarray((alg._min_value if largest else alg._max_value)(
+        keys.dtype), keys.dtype)
+    vals = _from_bits(cand.reshape(S, k), keys.dtype, kb, largest)
+    return jnp.where(valid, vals, fill), idx.reshape(S, k)
+
+
+@ki.sub_backend_alias
+def top_k_radix(keys, k, *, largest=True, key_bits=None, backend="xla",
+                policy=None):
+    """(values, indices) of the k extreme elements, sorted, ties stable:
+    the one-segment radix select."""
+    n = keys.shape[0]
+    if not 0 <= k <= n:
+        raise ValueError(f"top_k: need 0 <= k <= n, got k={k}, n={n}")
+    policy = _resolve_policy(policy, backend)
+    kb = _key_bits_for(keys, key_bits)
+    if k == 0:
+        return keys[:0], jnp.zeros((0,), jnp.int32)
+    vals, idx = _select_top_k(
+        keys, None, jnp.asarray([0, n], jnp.int32), k=k, num_segments=1,
+        largest=largest, kb=kb, backend=backend, policy=policy)
+    return vals[0], idx[0]
+
+
 @ki.sub_backend_alias
 def segmented_top_k_radix(keys, k, *, flags=None, offsets=None,
                           num_segments=None, largest=True, key_bits=None,
@@ -366,35 +482,14 @@ def segmented_top_k_radix(keys, k, *, flags=None, offsets=None,
     if k < 0:
         raise ValueError(f"top_k: k must be >= 0, got {k}")
     n = keys.shape[0]
-    scan = ki.resolve_impl("scan@flat", backend)
     if offsets is not None:
         num_segments = int(offsets.shape[0]) - 1
-        offs = offsets.astype(jnp.int32)
-    else:
-        seg_ids = seg_k.flags_to_segment_ids(flags, backend=backend,
-                                             policy=policy)
-        counts = jnp.zeros((num_segments,), jnp.int32).at[seg_ids].add(
-            1, mode="drop")
-        csum = scan(alg.ADD, counts, policy=policy)
-        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), csum])
-    counts = offs[1:] - offs[:-1]
-
     fill = alg.full_like_spec(
         jax.ShapeDtypeStruct((num_segments, k), keys.dtype),
         alg._min_value(keys.dtype) if largest else alg._max_value(keys.dtype))
-    if n == 0 or k == 0:
+    if n == 0 or k == 0 or num_segments == 0:
         return fill, jnp.full((num_segments, k), -1, jnp.int32)
-
-    iota = jnp.arange(n, dtype=jnp.int32)
-    sorted_keys, (perm,), starts = _segmented_sort_core(
-        keys, (iota,), flags=flags, offsets=offsets, descending=largest,
-        key_bits=key_bits, backend=backend, policy=policy,
-        carry_starts=True)
-    within = perm - starts
-
-    pos = offs[:-1, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
-    safe = jnp.clip(pos, 0, n - 1)
-    vals = jnp.where(valid, sorted_keys[safe], fill)
-    idx = jnp.where(valid, within[safe], -1)
-    return vals, idx
+    return _select_top_k(
+        keys, flags, offsets, k=k, num_segments=num_segments,
+        largest=largest, kb=_key_bits_for(keys, key_bits), backend=backend,
+        policy=policy)

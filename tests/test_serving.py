@@ -7,6 +7,7 @@ import pytest
 from repro.configs import base as C
 from repro.models import lm
 from repro.serving.engine import Engine, Request, sample_tokens
+from repro.serving.sampling import request_step_keys
 
 
 @pytest.fixture(scope="module")
@@ -145,6 +146,52 @@ def test_nucleus_first_candidate_always_survives():
     logits = rng.normal(size=128).astype(np.float32)
     draws = _sample_draws(logits, top_k=8, top_p=1e-6)
     assert (draws == int(np.argmax(logits))).all()
+
+
+def _nucleus_by_sort(key, logits, seeds, steps, *, temperature, top_p, k):
+    """The pinned semantics in numpy: a stable descending sort keeps the
+    first k (ties by index), the nucleus keeps the renormalized prefix
+    below top_p, and the same counter-based Gumbel draw picks."""
+    keys = request_step_keys(key, jnp.asarray(seeds), jnp.asarray(steps))
+    g = np.asarray(jax.vmap(
+        lambda kk: jax.random.gumbel(kk, (k,), jnp.float32))(keys))
+    order = np.argsort(-logits, axis=1, kind="stable")[:, :k]
+    scaled = np.take_along_axis(logits, order, 1) / np.float32(temperature)
+    e = np.exp(scaled - scaled[:, :1])
+    probs = e / e.sum(axis=1, keepdims=True)
+    cum = np.cumsum(probs, axis=1) - probs
+    choice = np.argmax(np.where(cum < top_p, scaled, -np.inf) + g, axis=1)
+    return order[np.arange(len(order)), choice]
+
+
+def test_sampler_matches_sort_composition_with_ties_at_the_cut():
+    """The code cell's sampler settings (temperature 0.2, top-p 0.95 over
+    64 candidates) on logits whose 55 leaders sit just above 16 ties at
+    ranks 55-70: the candidate cut falls inside the ties and the nucleus
+    keeps ranks 0-60, so the tokens show which tied logits were kept."""
+    B, V, K = 8, 4096, 64
+    rng = np.random.default_rng(21)
+    logits = (rng.normal(size=(B, V)) - 10.0).astype(np.float32)
+    tied = []
+    for b in range(B):
+        ids = rng.permutation(V)
+        logits[b, ids[:55]] = 5.0 + rng.uniform(1e-4, 1e-3, 55)
+        logits[b, ids[55:71]] = 5.0
+        tied.append(ids[55:71])
+    seeds = np.arange(B, dtype=np.int32) * 7 + 3
+    key = jax.random.PRNGKey(5)
+    hits = 0
+    for step in range(8):
+        steps = np.full(B, step, np.int32)
+        got = sample_tokens(key, jnp.asarray(logits), jnp.asarray(seeds),
+                            jnp.asarray(steps), temperature=0.2, top_k=0,
+                            top_p=0.95, top_p_candidates=K)
+        want = _nucleus_by_sort(key, logits, seeds, steps, temperature=0.2,
+                                top_p=0.95, k=K)
+        np.testing.assert_array_equal(np.asarray(got), want,
+                                      err_msg=f"step {step}")
+        hits += sum(int(want[b] in tied[b]) for b in range(B))
+    assert hits > 0, "no draw landed on a tied logit: the ties go untested"
 
 
 @pytest.fixture

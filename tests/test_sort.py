@@ -325,3 +325,137 @@ def test_sort_under_jit():
     rv, ri = ref.ref_top_k(k, 8)
     np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+
+
+# ---------------------------------------------------------------------------
+# top-k by radix select: the contract of the sort-based composition it
+# replaced, case by case, under jit.
+# ---------------------------------------------------------------------------
+
+SPECIALS = [np.nan, -np.nan, np.inf, -np.inf, 0.0, -0.0]
+
+
+def _select_keys(kind, n, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "ties":        # few values: many keys equal the k-th one
+        return jnp.asarray(rng.integers(0, 7, n).astype(np.float32))
+    if kind == "specials":    # every special, many times, amid ties
+        x = rng.integers(-2, 3, n).astype(np.float32)
+        x[rng.integers(0, n, n // 3)] = rng.choice(SPECIALS, n // 3)
+        return jnp.asarray(x)
+    if kind == "equal":
+        return jnp.full((n,), 1.5, jnp.float32)
+    if kind == "bf16":
+        return jnp.asarray(rng.integers(-3, 4, n).astype(np.float32)
+                           ).astype(jnp.bfloat16).at[4].set(jnp.nan)
+    if kind == "int32":
+        return jnp.asarray(rng.choice(
+            [np.iinfo(np.int32).min, -5, 0, 3, np.iinfo(np.int32).max], n),
+            jnp.int32)
+    if kind == "uint32":      # 10 significant bits
+        return jnp.asarray(rng.integers(0, 12, n) * 80, jnp.uint32)
+    raise ValueError(kind)
+
+
+# name -> (keys, k, descriptor, key_bits, largest); descriptor None is flat,
+# a list is CSR offsets, ("flags", offsets, num_segments) is the flag form.
+SELECT_CASES = {
+    "flat-ties-k17": ("ties", 17, None, None, True),
+    "flat-ties-smallest-k17": ("ties", 17, None, None, False),
+    "flat-specials-k40": ("specials", 40, None, None, True),
+    "flat-k1": ("ties", 1, None, None, True),
+    "flat-k-len": ("specials", 300, None, None, False),
+    "flat-int32-k33": ("int32", 33, None, None, True),
+    "flat-uint32-key-bits": ("uint32", 50, None, 10, False),
+    "seg-ties-k9": ("ties", 9, OFFSETS, None, True),
+    "seg-specials-smallest-k9": ("specials", 9, OFFSETS, None, False),
+    "seg-equal-k5": ("equal", 5, OFFSETS, None, True),
+    "seg-k0": ("ties", 0, OFFSETS, None, True),
+    "seg-k1": ("specials", 1, OFFSETS, None, True),
+    "seg-k-over-len": ("ties", 400, OFFSETS, None, True),
+    "seg-empty-ends": ("specials", 6, [0, 0, 50, 50, 300, 300], None, True),
+    "seg-bf16": ("bf16", 12, OFFSETS, None, True),
+    "seg-uint32-key-bits": ("uint32", 20, OFFSETS, 10, True),
+    "flags-never-started": ("ties", 9, ("flags", OFFSETS, 8), None, True),
+    "flags-fewer-segments": ("int32", 9, ("flags", OFFSETS, 3), None, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_top_k_select_matches_oracle(backend, case):
+    kind, k, desc, key_bits, largest = SELECT_CASES[case]
+    n = 300
+    keys = _select_keys(kind, n, seed=len(case))
+    kw = {"largest": largest, "key_bits": key_bits, "backend": backend}
+    if desc is None:
+        got = jax.jit(lambda x: forge.top_k(x, k, **kw))(keys)
+        want = ref.ref_top_k(keys, k, largest=largest)
+    elif isinstance(desc, list):
+        got = jax.jit(lambda x, o: forge.top_k(
+            x, k, layout=Segmented(offsets=o), **kw))(
+                keys, jnp.asarray(desc, jnp.int32))
+        want = ref.ref_segmented_top_k(keys, k, offsets=desc,
+                                       largest=largest)
+    else:
+        _, offs, ns = desc
+        flags = _flags_from_offsets(offs, n)
+        got = jax.jit(lambda x, f: forge.top_k(
+            x, k, layout=Segmented(flags=f, num_segments=ns), **kw))(
+                keys, flags)
+        want = ref.ref_segmented_top_k(keys, k, flags=np.asarray(flags),
+                                       num_segments=ns, largest=largest)
+    assert got[0].dtype == keys.dtype and got[1].dtype == jnp.int32
+    assert got[0].shape == want[0].shape == got[1].shape
+    _equal_with_nans(got[0], want[0], err=case)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]),
+                                  err_msg=case)
+
+
+@pytest.mark.parametrize("rows", [8, 1])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_top_k_select_sampler_shape(backend, rows):
+    """The sampler's call: rows x 32256 f32 logits, 64 candidates, ties
+    planted across the 64th candidate of every row."""
+    V, k = 32256, 64
+    rng = np.random.default_rng(rows)
+    x = rng.normal(size=(rows, V)).astype(np.float32)
+    for r in range(rows):
+        ids = rng.permutation(V)
+        x[r, ids[:50]] = 6.0 + rng.uniform(0, 1, 50)
+        x[r, ids[50:90]] = 6.0
+    flat = jnp.asarray(x.reshape(-1))
+    offsets = jnp.arange(rows + 1, dtype=jnp.int32) * V
+    v, i = jax.jit(lambda f, o: forge.top_k(
+        f, k, layout=Segmented(offsets=o), backend=backend))(flat, offsets)
+    rv, ri = ref.ref_segmented_top_k(flat, k, offsets=np.asarray(offsets))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+
+
+def test_top_k_select_holds_no_per_bucket_array():
+    """The TPU route's program at the sampler's 8 x 32256, k = 64 holds no
+    array of 16 n elements or more: selection counts, it never spreads the
+    stream over digit buckets (the sort's one-hot was 256 n)."""
+    from repro.core import intrinsics as ki
+    n = 8 * 32256
+    offsets = jnp.arange(9, dtype=jnp.int32) * 32256
+    jaxpr = jax.make_jaxpr(lambda f: ki.resolve_impl(
+        "top_k@segmented", "pallas-tpu")(
+            f, 64, offsets=offsets, flags=None, num_segments=None,
+            policy=ki.resolve_tuning("tpu_v5e")))(
+                jax.ShapeDtypeStruct((n,), jnp.float32))
+
+    def sizes(jx):
+        for eqn in jx.eqns:
+            for var in eqn.outvars:
+                yield int(np.prod(getattr(var.aval, "shape", ())))
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if isinstance(inner, jax.extend.core.Jaxpr):
+                        yield from sizes(inner)
+
+    largest = max(sizes(jaxpr.jaxpr))
+    assert n <= largest < 16 * n, largest
